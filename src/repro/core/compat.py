@@ -1,59 +1,27 @@
-"""Version-tolerant wrappers for JAX APIs that moved between releases.
+"""The JAX API surface this repo is written against (JAX 0.9).
 
-Two seams matter to this repo:
+Every module imports these names from here, never from jax directly, so a
+version bump is a one-file change:
 
-  - ``shard_map`` lives at ``jax.shard_map`` (new) or
-    ``jax.experimental.shard_map.shard_map`` (<= 0.4.x), and the replication
-    check kwarg was renamed ``check_rep`` -> ``check_vma``.
-  - ``jax.set_mesh`` (new) supersedes entering the ``Mesh`` object itself as a
-    context manager.
-
-Every module in this repo imports these names from here, never from jax
-directly, so a version bump is a one-file change.
+  - ``shard_map`` is ``jax.shard_map`` (replication check: ``check_vma``);
+  - ``make_mesh`` builds a mesh whose axes are all ``AxisType.Auto``;
+  - ``set_mesh`` is ``jax.set_mesh``, the ambient-mesh context manager;
+  - ``cost_analysis`` is ``Compiled.cost_analysis()``, a flat dict.
 """
 from __future__ import annotations
 
 import jax
 
-try:                                        # jax >= 0.6
-    from jax import shard_map as _shard_map
-    _NEW_SHARD_MAP = True
-except ImportError:                         # jax <= 0.4.x / 0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _NEW_SHARD_MAP = False
+shard_map = jax.shard_map
+set_mesh = jax.set_mesh
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-    """``jax.shard_map`` with the new-API surface on every jax version."""
-    if check_vma is not None:
-        kwargs["check_vma" if _NEW_SHARD_MAP else "check_rep"] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
-
-
-def make_mesh(axis_shapes, axis_names, *, auto_axes: bool = True):
-    """``jax.make_mesh`` with Auto axis types where the release supports
-    them (``axis_types`` landed well after ``make_mesh`` itself)."""
-    if auto_axes and hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            axis_shapes, axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
+def make_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict on every jax version
-    (older releases return a one-element list of per-computation dicts)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
-
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh        # Mesh is itself a context manager on old releases
+    return compiled.cost_analysis()

@@ -11,10 +11,12 @@ fp32 VMEM, and only the [B, D] result is written out.
 This is the same AMAC-style dependence-breaking as neighbor_lookup.py, in its
 simplest form (fixed-length chains of 1): a warm-up for the full probe kernel.
 
-Layout notes (TPU): rows are (1, D) DMAs — D should be a multiple of 128 for
-lane alignment on real hardware (the recsys dims 10/18/32 are padded by
-ops.py).  Indices arrive via scalar prefetch (SMEM) so the DMA addresses are
-known before the grid body runs.
+Layout notes (TPU): rows are (1, D) DMAs, and the TPU compiler accepts a
+row slice only when D is a multiple of 128 lanes.  Nothing pads D: padding
+would copy the whole table on every call, so a native call with any other D
+raises (the recsys dims 10/18/32 take ``impl="ref"``).  Indices and weights
+sit whole in SMEM so the DMA addresses are known before the grid body runs;
+a batch whose [B, L] arrays would not fit there raises too.
 """
 from __future__ import annotations
 
@@ -29,6 +31,19 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import ref as _ref
 
 _NSLOTS = 2     # double buffer
+_LANES = 128
+_SMEM_BYTES = 1 << 20         # v5e scalar memory per core
+_SMEM_RESERVED = 4 << 10      # the compiler keeps ~1.1 KiB; the rest is margin
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def smem_bytes(bsz: int, bag_len: int) -> int:
+    """SMEM the [B, L] int32 indices and fp32 weights take: the compiler
+    tiles each 2-D SMEM array to (8, 128) words."""
+    return 2 * _round_up(bsz, 8) * _round_up(bag_len, _LANES) * 4
 
 
 def _bag_kernel(idx_ref, wgt_ref, table_ref, out_ref, acc_ref, row_ref, sem,
@@ -79,15 +94,27 @@ def _bag_kernel(idx_ref, wgt_ref, table_ref, out_ref, acc_ref, row_ref, sem,
 def embedding_bag(table: jnp.ndarray, indices: jnp.ndarray,
                   weights: Optional[jnp.ndarray] = None, *,
                   mode: str = "sum", bags_per_block: int = 8,
-                  interpret: bool = True) -> jnp.ndarray:
+                  interpret: bool = False) -> jnp.ndarray:
     """table: [V, D]; indices: int32 [B, L] (-1 pad); weights: [B, L] or None.
-    Returns fp32 [B, D].  B must divide by bags_per_block (ops.py pads)."""
+    Returns fp32 [B, D].  B must divide by bags_per_block (ops.py pads).
+    Compiled for the TPU, D must be a multiple of 128 and the [B, L] arrays
+    must fit SMEM (``smem_bytes``); either violation raises ValueError."""
     if mode not in ("sum", "mean"):
         raise ValueError(mode)
     bsz, bag_len = indices.shape
     _, d = table.shape
     if bsz % bags_per_block:
         raise ValueError(f"B={bsz} % bags_per_block={bags_per_block} != 0")
+    if not interpret:
+        if d % _LANES:
+            raise ValueError(f"D={d} is not a multiple of {_LANES}: the TPU "
+                             f"compiler refuses a (1, {d}) row DMA")
+        need = smem_bytes(bsz, bag_len)
+        if need > _SMEM_BYTES - _SMEM_RESERVED:
+            raise ValueError(
+                f"[B, L]=[{bsz}, {bag_len}] indices and weights take {need} B "
+                f"of SMEM, over the {_SMEM_BYTES - _SMEM_RESERVED} B "
+                f"available: split the batch")
     if weights is None:
         weights = jnp.ones((bsz, bag_len), jnp.float32)
     grid = (bsz // bags_per_block,)

@@ -17,6 +17,10 @@ Two kernels, mirroring the paper's Figure 9 regimes:
   semaphores playing the MSHR role (DESIGN.md §2).
 
 Both validated in interpret mode against kernels/ref.py; ops.py dispatches.
+Neither compiles for the TPU yet (JAX 0.9 / v5e): Mosaic supports only 2-D
+gathers, which ``lookup_vec``'s ``jnp.take`` is not, and ``lookup_amac``
+stores scalars to VMEM.  On the chip ``ops.neighbor_lookup`` therefore runs
+the engine's probe (core/lookup.py) under ``impl="auto"``.
 """
 from __future__ import annotations
 
@@ -104,7 +108,7 @@ def _vec_kernel(khi_ref, klo_ref, vhi_ref, vlo_ref, qhi_ref, qlo_ref,
 @functools.partial(jax.jit, static_argnames=("capacity", "max_probes",
                                              "block_q", "interpret"))
 def lookup_vec(key_hi, key_lo, val_hi, val_lo, q_hi, q_lo, *, capacity: int,
-               max_probes: int, block_q: int = 512, interpret: bool = True):
+               max_probes: int, block_q: int = 512, interpret: bool = False):
     n = q_hi.shape[0]
     if n % block_q:
         raise ValueError(f"N={n} % block_q={block_q} != 0 (pad at call site)")
@@ -234,7 +238,7 @@ def _amac_kernel(qhi_ref, qlo_ref, lines_ref, found_ref, phi_ref, plo_ref,
     "capacity", "bpl", "max_probes", "block_q", "n_slots", "interpret"))
 def lookup_amac(lines, q_hi, q_lo, *, capacity: int, bpl: int,
                 max_probes: int, block_q: int = 256, n_slots: int = 8,
-                interpret: bool = True):
+                interpret: bool = False):
     """lines: uint32 [n_lines, 4, bpl] (pack_lines); queries uint32 [N].
     Returns (found u32[N], p_hi u32[N], p_lo u32[N])."""
     n = q_hi.shape[0]

@@ -1,11 +1,15 @@
 """jit'd dispatch wrappers for the Pallas kernels.
 
-Backend policy:
-  * on TPU: Pallas kernels compiled natively (interpret=False);
-  * elsewhere (this container): ``impl='ref'`` pure-jnp oracles by default —
-    models and the dry-run always lower through XLA;
-  * ``impl='vec'|'amac'|'pallas'``: force the kernel (interpret mode off-TPU)
-    — used by tests and the Fig-9 benchmark.
+Backend policy, chosen from ``jax.devices()[0].platform``:
+  * ``tpu``: ``impl='auto'`` runs the native kernel (interpret=False), except
+    the neighbor probe: the TPU compiler refuses ``lookup_vec`` (1-D gather)
+    and ``lookup_amac`` (scalar stores to VMEM), so ``auto`` runs ``ref``
+    there, which is the engine's own probe (core/lookup.py).  A forced impl
+    compiles natively.
+  * ``cpu``: ``impl='auto'`` runs the pure-jnp oracle (``ref``); a forced
+    ``impl='vec'|'amac'|'pallas'`` runs the kernel in interpret mode — what
+    the parity tests do.
+  * any other platform raises: no kernel silently falls back there.
 
 All wrappers handle padding to the kernels' block multiples.
 """
@@ -24,8 +28,21 @@ from repro.kernels import fused_fm as _fm
 from repro.kernels import neighbor_lookup as _nl
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _platform() -> str:
+    platform = jax.devices()[0].platform
+    if platform not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"no kernel policy for platform {platform!r}: kernels run "
+            f"natively on tpu and as ref/interpret on cpu")
+    return platform
+
+
+def _resolve(impl: str, tpu_impl: str) -> tuple[str, bool]:
+    """-> (impl, interpret) for this platform."""
+    on_tpu = _platform() == "tpu"
+    if impl == "auto":
+        impl = tpu_impl if on_tpu else "ref"
+    return impl, not on_tpu
 
 
 def _pad_to(x: jnp.ndarray, mult: int, axis: int = 0, fill=0):
@@ -46,12 +63,10 @@ def neighbor_lookup(key_hi, key_lo, val_hi, val_lo, q_hi, q_lo, *,
                     block_q: int = 256, n_slots: int = 8):
     """Returns (found u32[N], p_hi u32[N], p_lo u32[N])."""
     capacity = key_hi.shape[0]
-    if impl == "auto":
-        impl = "vec" if _on_tpu() else "ref"
+    impl, interpret = _resolve(impl, "ref")
     if impl == "ref":
         return _ref.neighbor_lookup(key_hi, key_lo, val_hi, val_lo,
                                     q_hi, q_lo, max_probes=max_probes)
-    interpret = not _on_tpu()
     if impl == "vec":
         qh, n = _pad_to(q_hi, block_q)
         ql, _ = _pad_to(q_lo, block_q)
@@ -77,23 +92,23 @@ def neighbor_lookup(key_hi, key_lo, val_hi, val_lo, q_hi, q_lo, *,
 # ---------------------------------------------------------------------------
 def embedding_bag(table, indices, weights=None, *, mode: str = "sum",
                   impl: str = "auto", bags_per_block: int = 8):
-    if impl == "auto":
-        impl = "pallas" if _on_tpu() else "ref"
+    """Native on TPU needs D % 128 == 0 and [B, L] within SMEM (the kernel
+    raises otherwise); pass ``impl="ref"`` for other shapes."""
+    impl, interpret = _resolve(impl, "pallas")
     if impl == "ref":
         return _ref.embedding_bag(table, indices, weights, mode)
     idx, n = _pad_to(indices, bags_per_block, fill=-1)
     w = None if weights is None else _pad_to(weights, bags_per_block)[0]
     out = _bag.embedding_bag(table, idx, w, mode=mode,
                              bags_per_block=bags_per_block,
-                             interpret=not _on_tpu())
+                             interpret=interpret)
     return out[:n]
 
 
 # ---------------------------------------------------------------------------
 def fm_interaction(emb, *, impl: str = "auto", block_b: int = 128):
-    if impl == "auto":
-        impl = "pallas" if _on_tpu() else "ref"
+    impl, interpret = _resolve(impl, "pallas")
     if impl == "ref":
         return _ref.fused_fm(emb)
     x, n = _pad_to(emb, block_b)
-    return _fm.fused_fm(x, block_b=block_b, interpret=not _on_tpu())[:n]
+    return _fm.fused_fm(x, block_b=block_b, interpret=interpret)[:n]

@@ -1,8 +1,9 @@
 """Pure-jnp oracles for every Pallas kernel in this package.
 
 Each kernel's tests sweep shapes/dtypes and assert_allclose against these.
-The oracles are also the default implementation on non-TPU backends (see
-ops.py), so the whole framework runs end-to-end on CPU.
+The oracles are also the default implementation on the CPU (see ops.py), so
+the whole framework runs end-to-end there; on the TPU, the neighbor-probe
+oracle is the engine's own probe (core/lookup.py).
 """
 from __future__ import annotations
 

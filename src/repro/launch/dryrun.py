@@ -25,6 +25,14 @@ import traceback
 from repro.core import compat
 
 
+def make_production_mesh(*, multi_pod: bool = False):
+    """Single pod: 16x16 = 256 chips (v5e pod).  Multi-pod: 2 pods x 256.
+    Only the dry-run builds it, on placeholder host devices."""
+    shape = (2, 16, 16) if multi_pod else (16, 16)
+    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
+    return compat.make_mesh(shape, axes)
+
+
 def _compile_bundle(mesh, bundle):
     import jax
     with compat.set_mesh(mesh):
@@ -106,7 +114,6 @@ def run_cell(arch_id: str, shape: str, multi_pod: bool, out_dir: str,
              fit_layers: bool = True) -> dict:
     import jax
     from repro.launch import cells as cells_mod
-    from repro.launch import mesh as mesh_mod
 
     tag = f"{arch_id}__{shape}__{'pod2' if multi_pod else 'pod1'}"
     if variant != "baseline":
@@ -122,7 +129,7 @@ def run_cell(arch_id: str, shape: str, multi_pod: bool, out_dir: str,
               "variant": variant, "ok": False}
     t0 = time.time()
     try:
-        mesh = mesh_mod.make_production_mesh(multi_pod=multi_pod)
+        mesh = make_production_mesh(multi_pod=multi_pod)
         bundle = cells_mod.build_cell(arch_id, shape, mesh, variant=variant)
         compiled = _compile_bundle(mesh, bundle)
         t_compile = time.time()

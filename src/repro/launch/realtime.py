@@ -350,6 +350,7 @@ def main():
                     help="write a BENCH-style JSON record (SLO report + "
                          "metrics snapshot) to this path on exit")
     args = ap.parse_args()
+    mesh_mod.enable_compile_cache()
     if args.smoke:
         args.n_items = min(args.n_items, 500)
         args.n_users = min(args.n_users, 64)

@@ -3,8 +3,8 @@
     PYTHONPATH=src python -m repro.launch.train --arch deepfm \
         [--smoke] [--steps 100] [--ckpt-dir artifacts/ckpt/deepfm]
 
-On this container (1 CPU device) use --smoke; on a real slice the same
-launcher builds the production mesh and runs the full config.
+The mesh spans the devices present.  --smoke runs the reduced config (CPU
+tests); without it the launcher runs the full config on those devices.
 """
 from __future__ import annotations
 
@@ -63,12 +63,12 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=25)
     args = ap.parse_args()
+    mesh_mod.enable_compile_cache()
 
     spec = registry.get(args.arch)
     shape = args.shape or {"lm": "train_4k", "gnn": "minibatch_lg",
                            "recsys": "train_batch"}[spec.family]
-    mesh = (mesh_mod.make_local_mesh() if args.smoke
-            else mesh_mod.make_production_mesh())
+    mesh = mesh_mod.make_local_mesh()
     bundle = cells_mod.build_cell(args.arch, shape, mesh, smoke=args.smoke)
     assert bundle.meta.get("has_opt"), f"{shape} is not a train cell"
     cfg = spec.smoke if args.smoke else spec.config

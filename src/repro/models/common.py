@@ -77,12 +77,13 @@ def adapt_spec(spec: P, mesh_axes) -> P:
 
 
 def cs(x, *spec_parts):
-    """with_sharding_constraint against the ambient mesh (no-op outside jit
-    or when the mesh has a single device)."""
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*spec_parts))
-    except Exception:
+    """with_sharding_constraint against the ambient mesh; a no-op where no
+    mesh is set, and outside jit, where a concrete array keeps its
+    placement.  Any other failure (an axis the mesh lacks) raises."""
+    if jax.sharding.get_abstract_mesh().empty or \
+            not isinstance(x, jax.core.Tracer):
         return x
+    return jax.lax.with_sharding_constraint(x, P(*spec_parts))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -82,6 +82,10 @@ def recsys_score_fn(cfg, mesh, mi, lookup_impl: str = "xla",
     if len(set(names)) != len(names):
         raise ValueError("duplicate table names in feature_fields: one "
                          "fused request carries one key set per table")
+    key_fields = {f for _, f in fields}
+    # the model runs as one compiled program; the store key columns are
+    # host-side inputs of the query and never reach it
+    model_step = jax.jit(step)
 
     def step_with_store(params, batch):
         n_rows = len(np.asarray(batch["dense"]))
@@ -108,9 +112,9 @@ def recsys_score_fn(cfg, mesh, mi, lookup_impl: str = "xla",
         dense = np.array(batch["dense"])
         d = min(feats.shape[1], dense.shape[1])
         dense[:, :d] = feats[:, :d]
-        batch = dict(batch)
+        batch = {k: v for k, v in batch.items() if k not in key_fields}
         batch["dense"] = jnp.asarray(dense)
-        return step(params, batch)
+        return model_step(params, batch)
 
     return step_with_store
 

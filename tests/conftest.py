@@ -1,9 +1,8 @@
-"""Shared test configuration: TPU-only paths skip (not error) off-TPU,
-plus the hermetic subprocess environment the launcher/bench smokes share.
+"""Shared test configuration: the hermetic subprocess environment the
+launcher/bench smokes share.  Nothing here touches a JAX backend: every
+xdist worker imports this file, and only one process may hold a chip.
 """
 import os
-
-import pytest
 
 
 def subprocess_env(pythonpath="src", inherit=False):
@@ -26,17 +25,3 @@ def subprocess_env(pythonpath="src", inherit=False):
         env.pop("PYTHONPATH", None)
     env["JAX_PLATFORMS"] = os.environ.get("JAX_PLATFORMS", "cpu")
     return env
-
-
-def pytest_collection_modifyitems(config, items):
-    try:
-        import jax
-        backend = jax.default_backend()
-    except Exception:                       # noqa: BLE001
-        backend = "none"
-    if backend == "tpu":
-        return
-    skip = pytest.mark.skip(reason=f"needs TPU backend (have {backend!r})")
-    for item in items:
-        if "tpu" in item.keywords:
-            item.add_marker(skip)

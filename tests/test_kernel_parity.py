@@ -167,13 +167,3 @@ def test_fm_interaction_parity(n_b):
     got = ops.fm_interaction(emb, impl="pallas", block_b=128)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.tpu
-@pytest.mark.parametrize("impl", ["vec", "amac"])
-def test_native_compilation_on_tpu(impl):
-    """Same parity, Pallas compiled natively (interpret=False).  Off-TPU
-    this is skipped by conftest, never errored."""
-    keys, _, t = _build(3000, seed=41)
-    q = _queries(keys, 512, 0.8, seed=2)
-    _run_both(t, q, impl)          # ops picks interpret=False on TPU

@@ -1,5 +1,6 @@
 """Pallas kernel sweeps vs pure-jnp oracles (interpret mode on CPU)."""
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -103,3 +104,47 @@ def test_pack_lines_layout():
     assert lines[0, 3, 7] == t.val_lo[7]
     # padding is EMPTY
     assert lines[-1, 0, -1] == hc.EMPTY_HI
+
+
+@pytest.mark.parametrize("shape", [((1000, 10), (64, 4)),       # D % 128
+                                   ((1000, 128), (1024, 20))])  # SMEM
+def test_embedding_bag_native_shape_checks(shape):
+    """Shapes the TPU compiler would refuse raise a ValueError naming the
+    cause, before any compile."""
+    from repro.kernels import embedding_bag as bag_mod
+    table, idx = (jax.ShapeDtypeStruct(s, d) for s, d in
+                  zip(shape, (jnp.float32, jnp.int32)))
+    with pytest.raises(ValueError, match="multiple of 128|SMEM"):
+        jax.eval_shape(lambda t, i: bag_mod.embedding_bag(t, i), table, idx)
+    assert bag_mod.smem_bytes(1016, 20) < (1 << 20) - (4 << 10)
+
+
+def test_fused_fm_native_needs_lane_multiple():
+    from repro.kernels import fused_fm as fm_mod
+    emb = jax.ShapeDtypeStruct((64, 39, 10), jnp.float32)
+    with pytest.raises(ValueError, match="lanes"):
+        jax.eval_shape(lambda e: fm_mod.fused_fm(e, block_b=32), emb)
+
+
+@pytest.mark.parametrize("platform,impl,expect", [
+    ("tpu", "auto", ("pallas", False)),
+    ("tpu", "ref", ("ref", False)),
+    ("cpu", "auto", ("ref", True)),
+    ("cpu", "pallas", ("pallas", True)),
+    ("gpu", "auto", RuntimeError),
+])
+def test_ops_platform_policy(monkeypatch, platform, impl, expect):
+    """auto runs the native kernel on TPU and ref on CPU; a forced impl
+    interprets only on CPU; any other platform raises."""
+    class _Dev:
+        pass
+    dev = _Dev()
+    dev.platform = platform
+    monkeypatch.setattr(ops.jax, "devices", lambda *a: [dev])
+    if expect is RuntimeError:
+        with pytest.raises(RuntimeError, match="no kernel policy"):
+            ops._resolve(impl, "pallas")
+    else:
+        assert ops._resolve(impl, "pallas") == expect
+    if platform == "tpu":
+        assert ops._resolve("auto", "ref") == ("ref", False)

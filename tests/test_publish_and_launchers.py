@@ -1,4 +1,5 @@
 """Delta publishing end-to-end + launcher (train/serve CLI) integration."""
+import json
 import subprocess
 import sys
 
@@ -97,3 +98,50 @@ def test_serve_launcher_feature_server_smoke():
 def test_dryrun_cli_help():
     r = _run("repro.launch.dryrun", "--help")
     assert r.returncode == 0 and "--multi-pod" in r.stdout
+
+
+def test_compile_cache_dir_from_env_or_checkout(monkeypatch, tmp_path):
+    """The env var wins untouched; otherwise the fixed checkout path."""
+    import jax
+    from repro.launch import mesh as mesh_mod
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert mesh_mod.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = str(mesh_mod.COMPILE_CACHE_DIR)
+        assert want.endswith("artifacts/jax_cache")
+        assert mesh_mod.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def _chip_smoke(*args):
+    return subprocess.run(
+        [sys.executable, "chip_smoke.py", *args],
+        capture_output=True, text=True, timeout=600,
+        env=subprocess_env(pythonpath=None))
+
+
+@pytest.mark.slow
+def test_chip_smoke_rehearsal_on_cpu():
+    """--small runs every phase on the CPU and checks every answer; its
+    last line reports the CPU and can never claim ok."""
+    r = _chip_smoke("--small")
+    assert r.returncode == 0, r.stderr[-2000:]
+    lines = r.stdout.strip().splitlines()
+    assert json.loads(lines[-1]) == {
+        "ok": False, "device": {"platform": "cpu", "kind": "cpu",
+                                "count": 1}}
+    out = r.stdout
+    assert out.count("all match the reference") == 2
+    assert "min_version(2) read-back" in out and "matches" in out
+    assert "native FM kernel False" in out
+
+
+def test_chip_smoke_refuses_without_tpu():
+    r = _chip_smoke()
+    assert r.returncode == 2
+    assert "no TPU" in r.stderr and r.stdout.strip() == ""
