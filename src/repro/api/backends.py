@@ -41,6 +41,7 @@ from repro.api.types import (Consistency, QueryRequest, QueryResult,
 # jax import; EngineBackend imports the engine lazily
 from repro.core.query_types import VersionEvictedError
 from repro.core.hybrid_store import HybridKVStore
+from repro.obs.trace import SpanSink, Tracer
 
 __all__ = ["BatchQueryBackend", "ClusterBackend", "EngineBackend",
            "FabricBackend", "StoreBackend", "as_backend"]
@@ -71,12 +72,20 @@ class BatchQueryBackend(Protocol):
 # ---------------------------------------------------------------------------
 class EngineBackend:
     """The fused multi-table engine behind the protocol — a thin adapter,
-    since the engine already speaks split-phase version-pinned batches."""
+    since the engine already speaks split-phase version-pinned batches.
+
+    A traced ``QueryServer`` hands it its tracer (``trace_into``): the
+    server then passes each batch's span sink to ``begin``/``finish``, and
+    every delta publish records its own ``publish_delta`` trace."""
 
     name = "engine"
 
     def __init__(self, engine):
         self.engine = engine
+        self.tracer: Optional[Tracer] = None
+
+    def trace_into(self, tracer: Tracer) -> None:
+        self.tracer = tracer
 
     @property
     def latest_version(self) -> int:
@@ -86,16 +95,22 @@ class EngineBackend:
     def table_names(self) -> list[str]:
         return self.engine.table_names
 
-    def begin(self, tables, *, version=None, strict=False):
-        return self.engine.begin(tables, version=version, strict=strict)
+    def begin(self, tables, *, version=None, strict=False, spans=None):
+        return self.engine.begin(tables, version=version, strict=strict,
+                                 spans=spans)
 
-    def finish(self, inflight) -> QueryResult:
-        return self.engine.finish(inflight)
+    def finish(self, inflight, spans=None) -> QueryResult:
+        return self.engine.finish(inflight, spans)
 
     def apply_update(self, update: UpdateRequest) -> None:
         if update.is_delta:
-            self.engine.publish_delta(update.version, update.upserts,
-                                      update.deletes)
+            spans = None if self.tracer is None else SpanSink(self.tracer)
+            try:
+                self.engine.publish_delta(update.version, update.upserts,
+                                          update.deletes, spans=spans)
+            finally:
+                if spans is not None:
+                    spans.flush()
         else:
             self.engine.publish(update.version, update.scalars,
                                 update.embeddings)

@@ -24,6 +24,12 @@ pipeline:
 
 Scalar lookups run on device through core/lookup.py; embedding tables resolve
 through core/hybrid_store.HybridKVStore (dedup also dedups NVMe IO).
+
+Given a span sink (``begin``/``finish``/``publish_delta``'s ``spans``), the
+engine records its own steps as spans (core/spans.py): ``begin.stage``,
+``begin.upload``, ``begin.dispatch``; ``finish.device_wait``,
+``finish.fetch``, ``finish.scalar_gather``, ``finish.row_gather`` and the
+hybrid store's ``store.*``; ``publish_delta`` and its ``publish.*`` steps.
 """
 from __future__ import annotations
 
@@ -48,7 +54,9 @@ from repro.core.query_types import (EmbeddingTable,  # noqa: F401
                                     QueryResult, ScalarTable, TableResult,
                                     VersionEvictedError)
 from repro.core.sharding import ShardPlan, TableSpec, plan_shards
+from repro.core.spans import step
 from repro.core.versioning import VersionWindow
+from repro.obs.trace import SpanSink
 
 
 @dataclasses.dataclass
@@ -134,28 +142,38 @@ class _FusedBuild:
 
     def _make_fused_fn(self, shard: int):
         """One jitted program probing EVERY scalar table of this shard —
-        the cross-table coalesced launch."""
+        the cross-table coalesced launch.  Its names are stable across
+        compiles, so a profiler trace can be read by them: the module is
+        ``jit_probe_fused`` and each table's probe sits in the scope
+        ``probe/<table>``."""
         fns = [lk.make_lookup_fn(t) for t in self.shard_tables[shard]]
+        names = list(self.scalar_names)
 
         @jax.jit
-        def fused(arrays_list, q_his, q_los):
-            return [fn(arrs, qh, ql)
-                    for fn, arrs, qh, ql in zip(fns, arrays_list,
-                                                q_his, q_los)]
+        def probe_fused(arrays_list, q_his, q_los):
+            out = []
+            for name, fn, arrs, qh, ql in zip(names, fns, arrays_list,
+                                               q_his, q_los):
+                with jax.named_scope(f"probe/{name}"):
+                    out.append(fn(arrs, qh, ql))
+            return out
 
-        return fused
+        return probe_fused
 
     # ------------------------------------------------------------------
     @classmethod
-    def from_delta(cls, prev: "_FusedBuild",
-                   upserts: dict, deletes: dict) -> "_FusedBuild":
+    def from_delta(cls, prev: "_FusedBuild", upserts: dict, deletes: dict,
+                   spans: Optional[SpanSink] = None) -> "_FusedBuild":
         """Copy-on-write build: only the shards a delta touches get new
         tables/arrays/fused programs; everything else is shared with
         ``prev``, so retaining both versions costs O(delta), not O(rows).
 
         ``upserts[name]`` is ``(keys, payloads)`` for scalar tables or
         ``(keys, value_rows)`` for embedding tables; ``deletes[name]`` is a
-        key array.  Upserts apply before deletes."""
+        key array.  Upserts apply before deletes.  ``spans`` records the
+        steps: ``publish.index`` and ``publish.upload`` per shard,
+        ``publish.retrace``, and per embedding table
+        ``publish.store_clone`` and ``publish.store_upsert``."""
         self = cls.__new__(cls)
         self.scalar_names = prev.scalar_names
         self.scalar_index = prev.scalar_index
@@ -202,10 +220,16 @@ class _FusedBuild:
         def build_shard(s: int) -> tuple[int, list[tuple]]:
             out = []
             for bi, ku, pu, kd in shard_work[s]:
-                tbl = nh.apply_delta(prev.shard_tables[s][bi], ku, pu, kd,
-                                     copy=True)
-                arrs = {k: jnp.asarray(v)
-                        for k, v in tbl.device_arrays().items()}
+                with step(spans, "publish.index", shard=s):
+                    tbl = nh.apply_delta(prev.shard_tables[s][bi], ku, pu,
+                                         kd, copy=True)
+                with step(spans, "publish.upload", shard=s):
+                    arrs = {k: jnp.asarray(v)
+                            for k, v in tbl.device_arrays().items()}
+                    if spans is not None:
+                        # traced, the span holds the copy itself rather
+                        # than its dispatch
+                        jax.block_until_ready(arrs)
                 out.append((bi, tbl, arrs))
             return s, out
 
@@ -232,14 +256,19 @@ class _FusedBuild:
         # fused programs bake max_probes/home_capacity statically; reuse
         # prev's compiled fn unless one of its tables' statics actually
         # changed (a small delta usually leaves max chain length alone, so
-        # even touched shards skip the retrace)
-        self._fused_fns = [
-            self._make_fused_fn(s)
-            if s in touched and any(
-                statics(a) != statics(b)
-                for a, b in zip(self.shard_tables[s], prev.shard_tables[s]))
-            else prev._fused_fns[s]
-            for s in range(n_shards)]
+        # even touched shards skip the retrace).  A rebuilt program
+        # compiles at its first launch, inside that batch's begin.dispatch
+        with step(spans, "publish.retrace") as st:
+            self._fused_fns = [
+                self._make_fused_fn(s)
+                if s in touched and any(
+                    statics(a) != statics(b)
+                    for a, b in zip(self.shard_tables[s],
+                                    prev.shard_tables[s]))
+                else prev._fused_fns[s]
+                for s in range(n_shards)]
+            st.tag(rebuilt=sum(f is not p for f, p in
+                               zip(self._fused_fns, prev._fused_fns)))
         self.shards_copied = len(touched)
         self.shards_shared = n_shards - len(touched)
 
@@ -248,12 +277,14 @@ class _FusedBuild:
             if self.table_kinds[name] != "embedding":
                 continue
             parent = prev.stores[name]
-            store = parent.clone(retire=False)
-            if name in upserts:
-                k, v = upserts[name]
-                store.upsert_batch(k, v, copy_on_write=True)
-            if name in deletes:
-                store.delete_batch(deletes[name])
+            with step(spans, "publish.store_clone", table=name):
+                store = parent.clone(retire=False)
+            with step(spans, "publish.store_upsert", table=name):
+                if name in upserts:
+                    k, v = upserts[name]
+                    store.upsert_batch(k, v, copy_on_write=True)
+                if name in deletes:
+                    store.delete_batch(deletes[name])
             self.stores[name] = store
             cloned_parents.append(parent)
         # hand over the write paths only now that EVERY table's delta
@@ -307,6 +338,7 @@ class _InflightBatch:
     staged: _StagedBatch
     device_out: dict[int, list]   # shard -> fused launch outputs (async)
     launches: int
+    spans: Optional[SpanSink] = None   # where finish's steps record
 
     # the backend protocol's coalesce-stats face (api/backends.py): every
     # backend's inflight object exposes these three, so the server's stats
@@ -380,7 +412,8 @@ class MultiTableEngine:
 
     def publish_delta(self, version: int,
                       upserts: Optional[dict] = None,
-                      deletes: Optional[dict] = None) -> None:
+                      deletes: Optional[dict] = None,
+                      spans: Optional[SpanSink] = None) -> None:
         """Install ``version`` as an incremental delta on the latest build
         (paper Fig 2, the Update Subsystem's minute-level publish path).
 
@@ -391,16 +424,22 @@ class MultiTableEngine:
         are copy-on-written — untouched shards share arrays and compiled
         lookup programs with the previous build, so retaining the old
         version for in-flight batches stays O(delta).  A batch pinned to
-        the previous version keeps reading the old rows bitwise."""
-        with self._publish_lock:
+        the previous version keeps reading the old rows bitwise.
+
+        ``spans`` records the whole publish as ``publish_delta`` and its
+        steps under it (``_FusedBuild.from_delta``, then
+        ``publish.install``)."""
+        with step(spans, "publish_delta", version=version) as pub, \
+                self._publish_lock:
             ok, _, prev = self.window.get(None)
             if not ok:
                 raise RuntimeError(
                     "publish_delta needs a published base version; call "
                     "publish() first")
             build = _FusedBuild.from_delta(prev, upserts or {},
-                                           deletes or {})
-            self.window.publish(version, build)
+                                           deletes or {}, pub.sink)
+            with step(pub.sink, "publish.install"):
+                self.window.publish(version, build)
         with self._stats_lock:
             self.stats.delta_publishes += 1
             self.stats.shards_copied += build.shards_copied
@@ -480,9 +519,12 @@ class MultiTableEngine:
                             embeddings=embeddings, keys_requested=requested,
                             keys_deviceside=deviceside)
 
-    def _launch(self, staged: _StagedBatch) -> _InflightBatch:
+    def _launch(self, staged: _StagedBatch,
+                spans: Optional[SpanSink] = None) -> _InflightBatch:
         """Device half: one fused launch per shard covering every scalar
-        table with keys there.  Returns without blocking on results."""
+        table with keys there.  Returns without blocking on results.
+        ``spans`` records ``begin.upload`` (query tiles padded and copied
+        to the device) and ``begin.dispatch`` (the fused call) per shard."""
         build = staged.build
         device_out: dict[int, list] = {}
         launches = 0
@@ -493,54 +535,69 @@ class MultiTableEngine:
             # the fused program's signature is the build's scalar order;
             # tables the request didn't touch get a minimal dummy tile so
             # a subset (or reordered) request never misindexes the outputs
-            arrays_list, q_his, q_los = [], [], []
-            for bi in range(len(build.scalar_names)):
-                st = by_build_idx.get(bi)
-                pos = st.shard_pos[s] if st is not None else ()
-                pad = _pad_len(len(pos))
-                qh = np.zeros(pad, dtype=np.uint32)
-                ql = np.zeros(pad, dtype=np.uint32)
-                if st is not None and len(pos):
-                    qh[:len(pos)] = st.uniq_hi[pos]
-                    ql[:len(pos)] = st.uniq_lo[pos]
-                arrays_list.append(build.shard_arrays[s][bi])
-                q_his.append(jnp.asarray(qh))
-                q_los.append(jnp.asarray(ql))
-            device_out[s] = build._fused_fns[s](arrays_list, q_his, q_los)
+            with step(spans, "begin.upload", shard=s):
+                arrays_list, q_his, q_los = [], [], []
+                for bi in range(len(build.scalar_names)):
+                    st = by_build_idx.get(bi)
+                    pos = st.shard_pos[s] if st is not None else ()
+                    pad = _pad_len(len(pos))
+                    qh = np.zeros(pad, dtype=np.uint32)
+                    ql = np.zeros(pad, dtype=np.uint32)
+                    if st is not None and len(pos):
+                        qh[:len(pos)] = st.uniq_hi[pos]
+                        ql[:len(pos)] = st.uniq_lo[pos]
+                    arrays_list.append(build.shard_arrays[s][bi])
+                    q_his.append(jnp.asarray(qh))
+                    q_los.append(jnp.asarray(ql))
+            with step(spans, "begin.dispatch", shard=s):
+                device_out[s] = build._fused_fns[s](arrays_list, q_his,
+                                                    q_los)
             launches += 1
         return _InflightBatch(staged=staged, device_out=device_out,
                               launches=launches)
 
     def _finish(self, inflight: _InflightBatch) -> QueryResult:
         """Block on device results; inverse-gather back to request order;
-        resolve embedding tables through their hybrid stores."""
+        resolve embedding tables through their hybrid stores.  Steps
+        recorded in ``inflight.spans``: ``finish.device_wait``,
+        ``finish.fetch``, ``finish.scalar_gather``, per embedding table the
+        store's ``store.*`` and ``finish.row_gather``."""
         staged = inflight.staged
         build = staged.build
+        spans = inflight.spans
         tables: dict[str, TableResult] = {}
         hits = 0
-        for st in staged.scalars:
-            found_u = np.zeros(st.owners.shape[0], dtype=bool)
-            payload_u = np.zeros(st.owners.shape[0], dtype=np.uint64)
-            for s, outs in inflight.device_out.items():
-                pos = st.shard_pos[s]
-                if not len(pos):
-                    continue
-                f, p_hi, p_lo = outs[st.build_index]
-                f = np.asarray(f)[:len(pos)].astype(bool)
-                p = (np.asarray(p_hi, dtype=np.uint64)[:len(pos)]
-                     << np.uint64(32)) | \
-                    np.asarray(p_lo, dtype=np.uint64)[:len(pos)]
-                found_u[pos] = f
-                payload_u[pos] = p
-            found = found_u[st.inverse]
-            payloads = payload_u[st.inverse]
-            hits += int(found.sum())
-            tables[st.name] = TableResult(found=found, payloads=payloads)
+        with step(spans, "finish.device_wait"):
+            jax.block_until_ready(inflight.device_out)
+        with step(spans, "finish.fetch"):
+            host_out = jax.device_get(inflight.device_out)
+        with step(spans, "finish.scalar_gather"):
+            for st in staged.scalars:
+                found_u = np.zeros(st.owners.shape[0], dtype=bool)
+                payload_u = np.zeros(st.owners.shape[0], dtype=np.uint64)
+                for s, outs in host_out.items():
+                    pos = st.shard_pos[s]
+                    if not len(pos):
+                        continue
+                    f, p_hi, p_lo = outs[st.build_index]
+                    n = len(pos)
+                    found_u[pos] = f[:n].astype(bool)
+                    payload_u[pos] = \
+                        (p_hi[:n].astype(np.uint64) << np.uint64(32)) | \
+                        p_lo[:n].astype(np.uint64)
+                found = found_u[st.inverse]
+                hits += int(found.sum())
+                tables[st.name] = TableResult(found=found,
+                                              payloads=payload_u[st.inverse])
         for se in staged.embeddings:
             store = build.stores[se.name]
-            found_u, vals_u = store.get_batch(se.uniq)
-            found = found_u[se.inverse]
-            values = vals_u[se.inverse]
+            # untraced, get_batch keeps its plain form, which wrappers of
+            # it rely on (the benchmark's planted faults)
+            found_u, vals_u = store.get_batch(se.uniq) if spans is None \
+                else store.get_batch(se.uniq, spans=spans)
+            with step(spans, "finish.row_gather", table=se.name):
+                found = found_u[se.inverse]
+                values = vals_u[se.inverse]
             hits += int(found.sum())
             tables[se.name] = TableResult(found=found, values=values)
         with self._stats_lock:
@@ -566,20 +623,27 @@ class MultiTableEngine:
 
     def begin(self, request: dict[str, np.ndarray],
               version: Optional[int] = None,
-              strict: bool = False) -> _InflightBatch:
+              strict: bool = False,
+              spans: Optional[SpanSink] = None) -> _InflightBatch:
         """Split-phase face for serving pipelines (serve/server.QueryServer):
         stage (host dedup + shard routing, pins the version for the batch's
         whole lifetime) and launch (async device dispatch) WITHOUT blocking
         on results.  ``finish`` blocks and scatters back.  The returned
         batch's build reference keeps its version's tables alive even if the
-        window evicts it mid-flight."""
-        return self._launch(self._stage(request, version, strict))
+        window evicts it mid-flight.  ``spans`` records ``begin.stage`` and
+        ``_launch``'s steps."""
+        with step(spans, "begin.stage"):
+            staged = self._stage(request, version, strict)
+        return self._launch(staged, spans)
 
-    def finish(self, inflight: _InflightBatch) -> QueryResult:
+    def finish(self, inflight: _InflightBatch,
+               spans: Optional[SpanSink] = None) -> QueryResult:
         """Second half of ``begin``: block on the device, inverse-gather to
         request order, resolve embedding tables.  Safe to call from a worker
         thread while another thread begins the next batch — that overlap is
-        the server's double buffering."""
+        the server's double buffering.  ``spans`` records ``_finish``'s
+        steps."""
+        inflight.spans = spans
         return self._finish(inflight)
 
     def query_stream(self, requests: Iterable[dict[str, np.ndarray]],

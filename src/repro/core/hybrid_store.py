@@ -35,7 +35,9 @@ import numpy as np
 
 from repro.core import hashcore as hc
 from repro.core import neighborhash as nh
+from repro.core.spans import step
 from repro.core.tiering import TierStats
+from repro.obs.trace import SpanSink
 
 TIER_BIT = 51
 TIER_MASK = 1 << TIER_BIT
@@ -210,14 +212,18 @@ class HybridKVStore:
     # ------------------------------------------------------------------
     # read path
     # ------------------------------------------------------------------
-    def get_batch(self, keys: Sequence[int], admit: bool = True
+    def get_batch(self, keys: Sequence[int], admit: bool = True,
+                  spans: Optional[SpanSink] = None
                   ) -> tuple[np.ndarray, np.ndarray]:
         """-> (found bool[n], values uint8[n, value_bytes]).
 
         One vectorized index probe over the whole batch
         (``NeighborHash.lookup_host_batch``, the numpy masked-advance loop);
         hot hits gather from memory; cold misses do one memmap IO each and
-        are optionally admitted to the hot tier."""
+        are optionally admitted to the hot tier.  ``spans`` (passed per
+        call, so a cloned store needs no wiring) records the steps:
+        ``store.probe``, ``store.hot_gather``, ``store.cold_read`` and
+        ``store.admit``."""
         keys = np.asarray(keys, dtype=np.uint64).ravel()
         with self._stats_lock:
             self._clock += 1
@@ -229,12 +235,14 @@ class HybridKVStore:
             seq0 = self._write_seq
             if seq0 & 1:
                 continue
-            found, out, cold, hot_slots = self._probe_and_gather(keys)
+            found, out, cold, hot_slots = self._probe_and_gather(keys,
+                                                                 spans)
             if self._write_seq == seq0:
                 break
         else:
             with self._lock:
-                found, out, cold, hot_slots = self._probe_and_gather(keys)
+                found, out, cold, hot_slots = self._probe_and_gather(keys,
+                                                                     spans)
         # LRU touch only AFTER the read validated: a discarded torn attempt
         # must leave no side effects, or a bogus recency stamp would keep
         # the wrong entry hot through the next eviction scan.  The array is
@@ -260,11 +268,13 @@ class HybridKVStore:
             # one batch must queue ONE admission (a second _admit would pop
             # a second hot slot and orphan the first); _admit re-derives
             # the slot under the lock
-            for k in dict.fromkeys(keys[cold].tolist()):
-                self._admit(int(k))
+            with step(spans, "store.admit"):
+                for k in dict.fromkeys(keys[cold].tolist()):
+                    self._admit(int(k))
         return found, out
 
-    def _probe_and_gather(self, keys: np.ndarray       # seqlock-read
+    def _probe_and_gather(self, keys: np.ndarray,      # seqlock-read
+                          spans: Optional[SpanSink] = None
                           ) -> tuple[np.ndarray, np.ndarray, np.ndarray,
                                      np.ndarray]:
         """One vectorized probe + tier-split gather (no stats, no
@@ -285,23 +295,26 @@ class HybridKVStore:
         # self.hot_capacity, which may already describe the replacement
         hot_values = self._hot_values
         out = np.zeros((len(keys), self.value_bytes), dtype=np.uint8)
-        found, payloads = index.lookup_host_batch(keys)
-        cold = found & ((payloads & np.uint64(TIER_MASK)) != 0)
-        hot = found & ~cold
+        with step(spans, "store.probe"):
+            found, payloads = index.lookup_host_batch(keys)
+            cold = found & ((payloads & np.uint64(TIER_MASK)) != 0)
+            hot = found & ~cold
         # slots are clipped (mirroring the device lookup's mode="clip"
         # takes): a torn payload read mid-mutation may carry an
         # out-of-range slot, and the gather must survive long enough for
         # the caller's seqlock check to discard and retry the batch
         hot_slots = np.empty(0, dtype=np.int64)
         if hot.any():
-            hot_slots = np.clip(payloads[hot].astype(np.int64), 0,
-                                hot_values.shape[0] - 1)
-            out[hot] = hot_values[hot_slots]
+            with step(spans, "store.hot_gather"):
+                hot_slots = np.clip(payloads[hot].astype(np.int64), 0,
+                                    hot_values.shape[0] - 1)
+                out[hot] = hot_values[hot_slots]
         if cold.any():
-            slots = np.clip(
-                (payloads[cold] & np.uint64(SLOT_MASK)).astype(np.int64),
-                0, cold_file.shape[0] - 1)
-            out[cold] = cold_file[slots]            # the one NVMe IO per row
+            with step(spans, "store.cold_read"):
+                slots = np.clip(
+                    (payloads[cold] & np.uint64(SLOT_MASK)).astype(np.int64),
+                    0, cold_file.shape[0] - 1)
+                out[cold] = cold_file[slots]        # the one NVMe IO per row
         return found, out, cold, hot_slots
 
     # ------------------------------------------------------------------

@@ -20,8 +20,10 @@ assumes, concurrently:
     through the same serialized version sequence.
 
 Event-append -> servable-version latency lands in the obs registry as
-the ``repro_stream_freshness_seconds`` histogram (plus publish spans via
-``--trace-sample``), and the run exits with a per-run SLO report:
+the ``repro_stream_freshness_seconds`` histogram (with ``--trace-sample``
+above 0, the server's tracer samples requests at that rate and records
+every delta publish as a ``publish_delta`` trace), and the run exits with
+a per-run SLO report:
 freshness p50/p99, staleness violations, updates/s, qps.  Exit is
 nonzero on any ``min_version`` violation, served-version regression, or
 pipeline-stage crash.
@@ -147,15 +149,9 @@ def drive(args, registry_obj, tracer) -> tuple[int, dict]:
     stats = StreamStats(slo_budget_s=args.slo_s)
     bridge_stream_stats(registry_obj, stats)
     bridge_server_stats(registry_obj, server.stats_snapshot)
+    # a traced server hands its tracer to the engine's backend, which
+    # records every delta publish as a ``publish_delta`` trace of its own
     publisher = VersionedPublisher(client, engine.latest_version, stats)
-
-    def publish_span(version, t0, t1, rows):
-        tid = tracer.sample()
-        if tid is not None:
-            tracer.span(tid, "publish_delta", t0, t1,
-                        tags={"version": version, "rows": rows})
-
-    publisher.on_publish = publish_span
 
     step_fn, holder = make_step_fn(args, cfg, mesh, mi, params)
     # pay the jit compiles before any event's clock starts (the second
@@ -345,7 +341,9 @@ def main():
                     help="serve Prometheus /metrics on this port while "
                          "driving (0 = ephemeral; the bound URL is printed)")
     ap.add_argument("--trace-sample", type=float, default=0.0,
-                    help="fraction of queries/publishes to trace [0,1]")
+                    help="fraction of queries to trace [0,1]; above 0 "
+                         "the server also records every micro-batch and "
+                         "every delta publish as a trace")
     ap.add_argument("--record", default=None,
                     help="write a BENCH-style JSON record (SLO report + "
                          "metrics snapshot) to this path on exit")
@@ -358,7 +356,10 @@ def main():
         args.requests = min(args.requests, 12)
 
     registry_obj = Registry()
-    tracer = Tracer(sample_rate=args.trace_sample, proc="realtime")
+    # a server with a tracer records every micro-batch and every delta
+    # publish: only an operator who asked for traces pays for them
+    tracer = (Tracer(sample_rate=args.trace_sample, proc="realtime")
+              if args.trace_sample > 0 else None)
     metrics_srv = None
     if args.metrics_port is not None:
         metrics_srv = MetricsServer(registry_obj,

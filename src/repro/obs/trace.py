@@ -1,15 +1,25 @@
-"""Sampled per-request spans across the serving pipeline.
+"""Sampled per-request spans, and every micro-batch's own spans, across
+the serving pipeline.
 
 Span taxonomy for one request through a ``QueryServer`` (names are part
 of the contract — ``docs/observability.md`` documents them, and
 ``tests/test_observability.py`` asserts the full chain):
 
     admission -> lane_wait -> coalesce -> version_pin -> begin
-              -> device -> finish -> scatter
+              -> finish_wait -> finish -> scatter
 
 under a per-request ``serve`` root span.  The Router adds ``route`` and
 per-shard ``shard_rpc`` spans and merges the shard-side span lists
 carried back in the wire response into one cross-process timeline.
+
+A batch that carries a traced request is also a trace of its own (a
+``SpanSink``): ``coalesce``, ``version_pin``, ``begin``,
+``finish_wait``, ``finish``.  An engine-backed server with a tracer
+records every micro-batch so, sampled or not, with the engine's and the
+hybrid store's steps under ``begin``/``finish`` (``begin.stage``,
+``finish.device_wait``, ``store.probe``, ...), and each delta publish as
+one ``publish_delta`` trace with its ``publish.*`` steps.
+``repro.core.spans.step`` times those steps.
 
 Timestamps are ``time.monotonic()``: CLOCK_MONOTONIC on Linux is a
 system-wide clock, so spans stamped in the router and in shard child
@@ -23,6 +33,7 @@ context — the decision is made once, at the outermost entry point.
 from __future__ import annotations
 
 import collections
+import itertools
 import os
 import random
 import threading
@@ -159,6 +170,53 @@ class Tracer:
     def trace_ids(self) -> List[str]:
         with self._lock:
             return list(self._order)
+
+
+class SpanSink:
+    """Where one unit of the program's own work (a micro-batch, a delta
+    publish) records its spans, under one trace id in ``tracer``; the
+    spans added here take ``parent_id``.  ``under`` gives the sink a
+    step's own sub-steps record into.
+
+    The sinks of one trace share what they hold and one span-id counter.
+    Spans are held until ``flush`` hands them to the tracer together: a
+    step on the serving path costs a tuple append, not a pass through the
+    tracer's lock."""
+
+    __slots__ = ("tracer", "trace_id", "parent_id", "_held", "_ids")
+
+    def __init__(self, tracer: Tracer, trace_id: Optional[str] = None,
+                 parent_id: Optional[str] = None,
+                 _held: Optional[list] = None, _ids=None):
+        self.tracer = tracer
+        self.trace_id = trace_id if trace_id is not None else new_id()
+        self.parent_id = parent_id
+        self._held = [] if _held is None else _held
+        self._ids = itertools.count(1) if _ids is None else _ids
+
+    def span_id(self) -> str:
+        """A span id unique within this trace."""
+        return f"{self.trace_id}.{next(self._ids)}"
+
+    def add(self, name: str, t0: float, t1: float,
+            span_id: Optional[str] = None,
+            tags: Optional[Dict[str, object]] = None) -> None:
+        self._held.append((name, t0, t1, span_id or self.span_id(),
+                           self.parent_id, tags))
+
+    def under(self, span_id: str) -> "SpanSink":
+        return SpanSink(self.tracer, self.trace_id, span_id, self._held,
+                        self._ids)
+
+    def flush(self) -> None:
+        """Record every span held so far in the tracer."""
+        held = self._held[:]
+        del self._held[:len(held)]
+        proc = self.tracer.proc
+        self.tracer.record([
+            Span(self.trace_id, name, t0, t1, parent_id=parent, proc=proc,
+                 span_id=span_id, tags=tags)
+            for name, t0, t1, span_id, parent, tags in held])
 
 
 def sort_timeline(spans: List[Span]) -> List[Span]:

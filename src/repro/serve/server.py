@@ -44,7 +44,8 @@ from typing import Optional
 
 from repro.api.backends import as_backend
 from repro.api.types import (ConsistencyError, QueryRequest, QueryResponse)
-from repro.obs.trace import Span, Tracer
+from repro.core.spans import step
+from repro.obs.trace import Span, SpanSink, Tracer
 from repro.serve.scheduler import (BatchPolicy, MicroBatcher, ServerStats,
                                    ServerClosedError, StatsSnapshot, Ticket,
                                    _Pending, coalesce, scatter)
@@ -67,12 +68,20 @@ class QueryServer:
             raise ValueError("pipeline_depth must be >= 1")
         if workers < 1:
             raise ValueError("workers must be >= 1")
-        # optional request tracing (obs/trace.py): with no tracer the only
-        # per-request cost is `is None` checks; with one, the tracer's
-        # sample() decides which fresh requests get a span timeline, and
-        # requests arriving with a trace context are always recorded
+        # optional tracing (obs/trace.py): with no tracer the only cost is
+        # `is None` checks; with one, the tracer's sample() decides which
+        # fresh requests get a span timeline, requests arriving with a
+        # trace context are always recorded, and a batch carrying such a
+        # request records its own span trace -- every batch does where the
+        # backend times its own steps
         self.tracer = tracer
         self.backend = as_backend(backend)
+        # a backend that times its own steps (the engine's) is handed the
+        # tracer, and each batch's begin/finish get the sink to record in
+        self._backend_spans = tracer is not None and \
+            hasattr(self.backend, "trace_into")
+        if self._backend_spans:
+            self.backend.trace_into(tracer)
         # legacy face: engine-backed servers keep their .engine attribute
         self.engine = getattr(self.backend, "engine", None)
         self.policy = policy or BatchPolicy()
@@ -295,16 +304,8 @@ class QueryServer:
                 return
             self._inflight.acquire()
             batch_id = next(self._batch_ids)
-            # batch-level trace timestamps, shared by every traced rider
-            # (coalesce/pin/begin/device/finish happen once per batch)
-            tinfo = None
-            if self.tracer is not None \
-                    and any(r.trace is not None for r in batch):
-                tinfo = {"formed": time.monotonic()}
-            fused, spans = coalesce(batch)
-            if tinfo is not None:
-                tinfo["coalesced"] = time.monotonic()
-            t_launch = time.monotonic()
+            fused, spans, bsink, coalesced = self._coalesce(batch)
+            t_launch = time.monotonic()     # the service-time clock
             # in-flight BEFORE begin: a request stalled inside a slow
             # backend.begin() must be visible to close()'s drain, or a
             # bounded close times out believing nothing is outstanding and
@@ -314,10 +315,8 @@ class QueryServer:
                 # begin pins ONE version for the whole micro-batch; the
                 # build reference keeps that version's tables alive even if
                 # a concurrent publish evicts it from the window mid-flight
-                inflight = self.backend.begin(
-                    fused, version=batch[0].version, strict=batch[0].strict)
-                if tinfo is not None:
-                    tinfo["begun"] = time.monotonic()
+                inflight, tinfo = self._begin(fused, batch, batch_id, bsink,
+                                              coalesced)
             except BaseException as e:  # noqa: BLE001
                 self._inflight.release()
                 self._inflight_reqs.pop(batch_id, None)
@@ -343,53 +342,110 @@ class QueryServer:
                 self._finish_batch(batch_id, batch, spans, inflight,
                                    t_launch, tinfo)
 
+    # ------------------------------------------------------------------
+    # one micro-batch's steps; a traced batch is a trace of its own
+    # (``SpanSink``), and the times of its coalesce/version_pin/begin/
+    # finish_wait/finish spans are shared by every traced rider's chain
+    # ------------------------------------------------------------------
+    def _coalesce(self, batch: list):
+        """``coalesce`` -> (fused, spans, bsink, coalesced).  A batch is
+        traced when the backend times its own steps (every batch: the
+        engine's spans are read per batch) or when a rider carries a trace
+        context; else ``bsink`` is None and nothing is recorded."""
+        bsink = None
+        if self._backend_spans or (self.tracer is not None and any(
+                r.trace is not None for r in batch)):
+            bsink = SpanSink(self.tracer)
+        with step(bsink, "coalesce") as sc:
+            fused, spans = coalesce(batch)
+        return fused, spans, bsink, sc
+
+    def _begin(self, fused: dict, batch: list, batch_id: int,
+               bsink: Optional[SpanSink], coalesced):
+        """``backend.begin`` for one micro-batch -> (inflight, tinfo).
+        Traced, it records ``begin`` (tagged with the batch's size) and the
+        ``version_pin`` before it, and ``tinfo`` holds the batch's times;
+        untraced, ``tinfo`` is None."""
+        version, strict = batch[0].version, batch[0].strict
+        try:
+            with step(bsink, "begin") as sb:
+                if self._backend_spans:
+                    inflight = self.backend.begin(
+                        fused, version=version, strict=strict,
+                        spans=sb.sink)
+                else:
+                    inflight = self.backend.begin(fused, version=version,
+                                                  strict=strict)
+                if bsink is not None:
+                    sb.tag(batch_id=batch_id, n_requests=len(batch),
+                           keys_requested=inflight.keys_requested,
+                           keys_deviceside=inflight.keys_deviceside)
+        except BaseException:
+            if bsink is not None:
+                bsink.flush()           # the batch ends here
+            raise
+        if bsink is None:
+            return inflight, None
+        bsink.add("version_pin", coalesced.t1, sb.t0)
+        return inflight, {"sink": bsink, "formed": coalesced.t0,
+                          "coalesced": coalesced.t1, "launch": sb.t0,
+                          "begun": sb.t1}
+
+    def _finish(self, inflight, tinfo: Optional[dict]):
+        """``backend.finish``; traced, it records ``finish`` and the
+        ``finish_wait`` from the return of ``begin`` until it started, and
+        hands the batch's spans to the tracer."""
+        bsink = None if tinfo is None else tinfo["sink"]
+        try:
+            with step(bsink, "finish") as sf:
+                if self._backend_spans:
+                    return self.backend.finish(inflight, sf.sink)
+                return self.backend.finish(inflight)
+        finally:
+            if tinfo is not None:
+                tinfo["finish_start"], tinfo["finish_end"] = sf.t0, sf.t1
+                bsink.add("finish_wait", tinfo["begun"], sf.t0)
+                bsink.flush()
+
     def _serve_single(self, req: _Pending) -> None:
         """Rare fallback: serve one request as its own micro-batch, inline
         on the scheduler thread (used when a fused begin() failed, to
         isolate a request-specific fault to its origin)."""
-        tinfo = None
-        if self.tracer is not None and req.trace is not None:
-            tinfo = {"formed": time.monotonic()}
-        fused, spans = coalesce([req])
-        if tinfo is not None:
-            tinfo["coalesced"] = time.monotonic()
+        batch_id = next(self._batch_ids)
+        fused, spans, bsink, coalesced = self._coalesce([req])
         t_launch = time.monotonic()
         try:
-            inflight = self.backend.begin(fused, version=req.version,
-                                          strict=req.strict)
-            if tinfo is not None:
-                tinfo["begun"] = time.monotonic()
-                tinfo["finish_start"] = tinfo["begun"]
-            result = self.backend.finish(inflight)
+            inflight, tinfo = self._begin(fused, [req], batch_id, bsink,
+                                          coalesced)
+            result = self._finish(inflight, tinfo)
         except BaseException as e:  # noqa: BLE001
             self.stats.on_failure(1, req.qos)
             req.ticket._fail(e)
             return
         now = time.monotonic()
-        if tinfo is not None:
-            tinfo["launch"] = t_launch
-            tinfo["finish_end"] = now
         self._batcher.observe_service_time(now - t_launch)
         self.stats.on_batch(1, inflight.keys_requested,
                             inflight.keys_deviceside, inflight.launches)
-        self._deliver(req, result, spans[0], next(self._batch_ids), now,
-                      tinfo)
+        self._deliver(req, result, spans[0], batch_id, now, tinfo)
 
     def _trace_spans(self, req: _Pending, tinfo: Optional[dict],
                      version: int, batch_id: int, t_scatter: float,
                      t_end: float) -> list:
         """Build this request's span timeline (obs/trace.py taxonomy:
         admission -> lane_wait -> coalesce -> version_pin -> begin ->
-        device -> finish -> scatter under a ``serve`` root), record it in
-        the tracer, and return the spans."""
+        finish_wait -> finish -> scatter under a ``serve`` root, whose
+        ``batch_trace`` tag names its micro-batch's own trace), record it
+        in the tracer, and return the spans."""
         tracer = self.tracer
         ctx = req.trace
         tid = ctx["trace_id"]
         proc = tracer.proc
+        tags = {"qos": req.qos.name, "batch_id": batch_id,
+                "version": version, "n_keys": req.n_keys}
+        if tinfo is not None:
+            tags["batch_trace"] = tinfo["sink"].trace_id
         root = Span(tid, "serve", req.t_submit, t_end,
-                    parent_id=ctx.get("parent_id"), proc=proc,
-                    tags={"qos": req.qos.name, "batch_id": batch_id,
-                          "version": version, "n_keys": req.n_keys})
+                    parent_id=ctx.get("parent_id"), proc=proc, tags=tags)
         pid = root.span_id
         # submit() stamps t_admit after admit() returns; a fast scheduler
         # can deliver before that lands — fall back to the submit stamp
@@ -401,7 +457,7 @@ class QueryServer:
                      ("coalesce", tinfo["formed"], tinfo["coalesced"]),
                      ("version_pin", tinfo["coalesced"], tinfo["launch"]),
                      ("begin", tinfo["launch"], tinfo["begun"]),
-                     ("device", tinfo["begun"], tinfo["finish_start"]),
+                     ("finish_wait", tinfo["begun"], tinfo["finish_start"]),
                      ("finish", tinfo["finish_start"],
                       tinfo["finish_end"]))
             for name, t0, t1 in chain:
@@ -449,9 +505,7 @@ class QueryServer:
                       tinfo: Optional[dict] = None) -> None:
         try:
             try:
-                if tinfo is not None:
-                    tinfo["finish_start"] = time.monotonic()
-                result = self.backend.finish(inflight)
+                result = self._finish(inflight, tinfo)
             except BaseException as e:  # noqa: BLE001
                 for req in batch:
                     self.stats.on_failure(1, req.qos)
@@ -460,9 +514,6 @@ class QueryServer:
             finally:
                 self._inflight.release()
             now = time.monotonic()
-            if tinfo is not None:
-                tinfo["launch"] = t_launch
-                tinfo["finish_end"] = now
             self._batcher.observe_service_time(now - t_launch)
             self.stats.on_batch(len(batch), inflight.keys_requested,
                                 inflight.keys_deviceside, inflight.launches,

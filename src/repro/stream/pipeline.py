@@ -134,10 +134,6 @@ class VersionedPublisher:
     def __init__(self, client, start_version: int, stats: StreamStats):
         self._client = client
         self._stats = stats
-        # optional (version, t0, t1, rows) hook — the launcher records a
-        # publish span per delta through it
-        self.on_publish: Optional[Callable[[int, float, float, int],
-                                           None]] = None
         self._lock = threading.Lock()
         self._version = int(start_version)   # guarded-by: _lock
 
@@ -150,7 +146,6 @@ class VersionedPublisher:
     def publish(self, upserts: dict, events: tuple | list = ()) -> int:
         rows = sum(len(k) for k, _ in upserts.values())
         with self._lock:
-            t0 = time.monotonic()
             v = self._version + 1
             self._client.update(v, upserts=upserts)
             self._version = v
@@ -159,9 +154,6 @@ class VersionedPublisher:
                 self._stats.observe_freshness(now - ev.t_append)
         self._stats.inc("deltas_published")
         self._stats.inc("rows_upserted", rows)
-        hook = self.on_publish
-        if hook is not None:
-            hook(v, t0, now, rows)
         return v
 
     def publish_full(self, *, scalars=(), embeddings=()) -> int:

@@ -319,7 +319,8 @@ class TestServerTracing:
         assert resp.trace, "sampled request returned no trace"
         names = [d["name"] for d in resp.trace]
         for want in ("serve", "admission", "lane_wait", "coalesce",
-                     "version_pin", "begin", "device", "finish", "scatter"):
+                     "version_pin", "begin", "finish_wait", "finish",
+                     "scatter"):
             assert want in names, f"missing span {want!r}"
         tids = {d["trace_id"] for d in resp.trace}
         assert len(tids) == 1
@@ -407,7 +408,7 @@ class TestFabricObservability:
             assert len(tids) == 1, f"trace ids fragmented: {tids}"
             for want in ("route", "shard_rpc", "serve", "admission",
                          "lane_wait", "coalesce", "version_pin", "begin",
-                         "device", "finish", "scatter"):
+                         "finish_wait", "finish", "scatter"):
                 assert want in names, f"missing span {want!r}"
             shard_procs = {p for p in procs if p.startswith("shard")}
             assert len(shard_procs) == 2, procs    # both shards contributed

@@ -96,6 +96,7 @@ def test_engine_probe_compiles_at_index_size(one_chip):
         home_capacity=INDEX_BUCKETS, stats=nh.BuildStats(max_chain_len=8))
     build = _FusedBuild.__new__(_FusedBuild)
     build.shard_tables = [[table]]
+    build.scalar_names = ["item_attr"]
     fused = build._make_fused_fn(0)
     arrays = {k: _sds(one_chip, (INDEX_BUCKETS,), jnp.uint32)
               for k in ("key_hi", "key_lo", "val_hi", "val_lo")}
@@ -103,3 +104,4 @@ def test_engine_probe_compiles_at_index_size(one_chip):
     compiled = fused.lower([arrays], [q], [q]).compile()
     assert compiled.memory_analysis().argument_size_in_bytes >= \
         16 * INDEX_BUCKETS
+    assert "jit_probe_fused" in compiled.as_text()
