@@ -172,7 +172,7 @@ class HybridKVStore:
                 payloads[i] = np.uint64(TIER_MASK | i)
         # slots never occupied at build time (e.g. hot_fraction=0, where
         # hot_capacity is clamped to 1) must start on the free list or the
-        # hot tier is permanently unusable — _admit would always bail
+        # hot tier is permanently unusable — admission would always bail
         self._hot_free = list(range(self.hot_capacity - 1, hot_slot - 1, -1))
         # guarded-by: _lock
         self._cold_slot_of_key_order = {int(k): i for i, k in enumerate(keys)}
@@ -181,7 +181,7 @@ class HybridKVStore:
                               buckets_per_line=buckets_per_line)  # guarded-by: _lock
         self._lock = threading.Lock()   # update-path only; reads lock-free
         # seqlock for the lock-free read path: every tier-moving mutation
-        # (_admit / eviction / value or index write) bumps this once on
+        # (admission / eviction / value or index write) bumps this once on
         # entry and once on exit under _lock, so it is odd while arrays are
         # mid-mutation; get_batch retries its probe+gather when the counter
         # moved, instead of risking a torn payload read (e.g. a cold->hot
@@ -264,13 +264,9 @@ class HybridKVStore:
             self.stats.hot_hits += n_hot
             self.stats.hot_bytes_read += n_hot * self.value_bytes
         if admit and n_cold:
-            # first-occurrence-ordered dedup: the same cold key twice in
-            # one batch must queue ONE admission (a second _admit would pop
-            # a second hot slot and orphan the first); _admit re-derives
-            # the slot under the lock
-            with step(spans, "store.admit"):
-                for k in dict.fromkeys(keys[cold].tolist()):
-                    self._admit(int(k))
+            with step(spans, "store.admit") as st:
+                n_cand, n_admitted = self._admit_batch(keys[cold])
+                st.tag(candidates=n_cand, admitted=n_admitted)
         return found, out
 
     def _probe_and_gather(self, keys: np.ndarray,      # seqlock-read
@@ -320,34 +316,60 @@ class HybridKVStore:
     # ------------------------------------------------------------------
     # tier movement (update path — serialized, like the Update Subsystem)
     # ------------------------------------------------------------------
-    def _admit(self, key: int):
-        with self._lock:
-            # re-check the payload tier under the lock: a concurrent admit
-            # (or an earlier admission of the same key) may have already
-            # moved it hot, and admitting twice would orphan a hot slot
-            ok, payload, _, _ = self.index.probe_trace(key)
-            if not ok or not (payload & TIER_MASK):
-                return
-            if not self._hot_free:
-                return          # hot tier full: eviction pass will make room
-            # closing bump in finally: an exception mid-write must not
-            # leave the seqlock odd forever (which would silently demote
-            # every future read to the serialized lock fallback)
+    def _admit_batch(self, cold_keys: np.ndarray) -> tuple[int, int]:
+        """Admit a batch's cold keys to the hot tier while free slots
+        last, in first-occurrence order; -> (candidates, admitted).
+
+        The same cold key twice in one batch is ONE candidate: a second
+        admission would pop a second hot slot and orphan the first.  With
+        no free slot (the steady state until an eviction pass runs) this
+        is a length check: no lock, no probe, no seqlock bump.  A stale
+        read of the free list can only skip an admission a later batch
+        makes; it never tears state."""
+        uniq, first = np.unique(cold_keys, return_index=True)
+        cand = uniq[np.argsort(first)]
+        admitted = 0
+        if self._hot_free:
+            with self._lock:
+                admitted = self._admit_locked(cand)
+        # counters live under _stats_lock (nested inside _lock where both
+        # are taken, the established order): a bare increment would race
+        # the reader-side stats writes in get_batch
+        with self._stats_lock:
+            self.stats.admit_candidates += len(cand)
+            self.stats.admissions += admitted
+        return len(cand), admitted
+
+    def _admit_locked(self, cand: np.ndarray) -> int:  # lock-held: _lock
+        if not self._hot_free:
+            return 0
+        # re-derive the payloads under the lock: a concurrent admission,
+        # eviction or delete may have moved a candidate since the probe,
+        # and admitting a key already hot would orphan a hot slot
+        found, payloads = self.index.lookup_host_batch(cand)
+        still_cold = found & ((payloads & np.uint64(TIER_MASK)) != 0)
+        m = min(int(still_cold.sum()), len(self._hot_free))
+        if m == 0:
+            return 0
+        keys = cand[still_cold][:m]
+        cold_slots = (payloads[still_cold][:m]
+                      & np.uint64(SLOT_MASK)).astype(np.int64)
+        # closing bump in finally: an exception mid-write must not leave
+        # the seqlock odd forever (which would silently demote every
+        # future read to the serialized lock fallback)
+        self._write_seq += 1
+        try:
+            # the slots pop() would hand out one key at a time, in order
+            slots = np.array(self._hot_free[-m:][::-1], dtype=np.int64)
+            del self._hot_free[-m:]
+            self._hot_values[slots] = self._cold[cold_slots]
+            self._hot_key[slots] = keys
+            self._hot_last_access[slots] = self._clock
+            # in place and offset-preserving, like _set_payload per key
+            self.index.update_batch(keys, slots.astype(np.uint64))
+        finally:
             self._write_seq += 1
-            try:
-                cold_slot = int(payload & np.uint64(SLOT_MASK))
-                hot_slot = self._hot_free.pop()
-                self._hot_values[hot_slot] = self._cold[cold_slot]
-                self._hot_key[hot_slot] = key
-                self._hot_last_access[hot_slot] = self._clock
-                self._set_payload(key, np.uint64(hot_slot))
-                # counters live under _stats_lock (nested inside _lock,
-                # the established order): a bare increment here would race
-                # the reader-side stats writes in get_batch
-                with self._stats_lock:
-                    self.stats.admissions += 1
-            finally:
-                self._write_seq += 1
+        return m
 
     def maintain(self, target_free_fraction: float = 0.05) -> int:
         """One asynchronous-eviction pass: scan LRU metadata of the hot tier
@@ -452,7 +474,7 @@ class HybridKVStore:
 
         Reads never block: the rewrite happens into a file invisible to
         readers, and only the final pointer swap sits inside the seqlock's
-        odd window.  Writers (``upsert_batch``/``delete_batch``/``_admit``/
+        odd window.  Writers (``upsert_batch``/``delete_batch``/admission/
         ``maintain``) serialize with the pass on the update lock."""
         with self._lock:
             # (garbage, size) snapshotted as one pair under the stats
@@ -966,7 +988,7 @@ class HybridKVStore:
                 raise RuntimeError(
                     "store already retired by a previous clone(); clone "
                     "the newest generation instead")
-            # snapshot under the lock: a concurrent _admit / eviction pass
+            # snapshot under the lock: a concurrent admission / eviction pass
             # mutating hot arrays + index mid-copy would tear the snapshot
             # (index says hot slot S, but S's bytes/key/free-list state are
             # from before the admission)

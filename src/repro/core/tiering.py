@@ -60,6 +60,9 @@ class TierStats:
     cold_misses: int = 0
     not_found: int = 0
     admissions: int = 0
+    # distinct cold keys of a batch offered to admission (admitted or
+    # not): admissions / admit_candidates is the admission share
+    admit_candidates: int = 0
     evictions: int = 0
     cold_bytes_read: int = 0
     hot_bytes_read: int = 0

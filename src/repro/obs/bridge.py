@@ -79,6 +79,7 @@ TIER_STATS_METRICS = {
     "cold_misses": "repro_tier_cold_misses_total",
     "not_found": "repro_tier_not_found_total",
     "admissions": "repro_tier_admissions_total",
+    "admit_candidates": "repro_tier_admit_candidates_total",
     "evictions": "repro_tier_evictions_total",
     "cold_bytes_read": "repro_tier_cold_bytes_read_total",
     "hot_bytes_read": "repro_tier_hot_bytes_read_total",
