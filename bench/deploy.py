@@ -1,4 +1,5 @@
-"""One deployment from its configuration file ``bench/configs/<config>.json``.
+"""The default deployment's data and build, from its configuration file
+``bench/configs/<config>.json`` (hooks: ``bench/deployments/default.py``).
 
 ``generate`` makes the tables' data from ``--seed`` (the benchmark's data,
 which both the program and the reference receive); ``make_deltas`` turns the

@@ -4,20 +4,23 @@
 
 The cell is looked up by name in ``BENCHMARK.json``; it names a
 configuration (``bench/configs/<config>.json``, per its ``file``) and a
-traffic mix (``bench/traffic/<traffic>.json``). Each metric the cell
-reports is read by its own reader, ``bench/metrics/<metric>.py``, whose
-``read(run)`` returns a number or None (nothing to read); see
-``metric_reader`` for a quantity split by what it moves.
+traffic mix (``bench/traffic/<traffic>.json``). The configuration's
+``deployment_module`` names the module of hooks that generates, builds,
+sends and compares for it (``bench/deployments/default.py`` where it names
+none; see ``deployment``). Each metric the cell reports is read by its own
+reader, ``bench/metrics/<metric>.py``, whose ``read(run)`` returns a number
+or None (nothing to read); see ``metric_reader`` for a quantity split by
+what it moves.
 
 One process does everything, because the chip belongs to one process:
 it makes the data from ``--seed``, builds the deployment through the
-program's own constructors, warms up every probe shape the cell's
-micro-batches reach, then drives the window (``bench/window.py``) with
-client threads and, where the mix has updates, a publisher thread. With
-``--trace 1`` the server records spans and a profiler trace is taken of a
-few seconds inside the window. Once the window has closed, device memory
-has been read and the program is freed, every answer is compared with the
-plain reference (``bench/reference.py``) at the version it reports.
+program's own constructors, warms up every shape the cell's traffic
+reaches, then drives the window (``bench/window.py``) with client threads
+and, where the mix has updates, a publisher thread. With ``--trace 1`` the
+server records spans and a profiler trace is taken of a few seconds inside
+the window. Once the window has closed, device memory has been read and
+the program is freed, every answer is compared with the deployment's plain
+reference at the version it reports.
 
 Without a TPU, or with fewer chips than the cell asks for, it exits 2 and
 prints no result. ``--control`` serves the window from the reference with
@@ -36,6 +39,7 @@ import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
@@ -48,10 +52,7 @@ ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, os.path.join(ROOT, "src"))
 sys.path.insert(0, BENCH)
 
-import deploy  # noqa: E402
-import reference  # noqa: E402
 import trace_reduce  # noqa: E402
-import traffic  # noqa: E402
 import window  # noqa: E402
 
 COMPILE_CACHE = os.path.join(ROOT, "artifacts", "jax_cache")
@@ -59,6 +60,8 @@ ROW_SAMPLE = 64                   # requests whose rows are compared
 TRACE_S = 4.0                     # profiled part of a --trace 1 window
 SPAN_SAMPLE = 0.1                 # share of requests the server traces
 BATCH_SPANS = ("coalesce", "version_pin", "begin", "finish", "scatter")
+DEFAULT_DEPLOYMENT = "deployments/default"
+MODULE_PATH = re.compile(r"[A-Za-z_][A-Za-z0-9_]*(/[A-Za-z_][A-Za-z0-9_]*)*")
 
 
 def load_json(path: str) -> dict:
@@ -77,6 +80,17 @@ def cell_files(spec: dict, name: str):
     return (cell, load_json(os.path.join(ROOT, cfg["file"])),
             load_json(os.path.join(BENCH, "traffic",
                                    cell["traffic"] + ".json")))
+
+
+def deployment(cfg: dict):
+    """The module of hooks for configuration ``cfg``: ``bench/<m>.py`` for
+    its ``"deployment_module": "<m>"``, or the default deployment."""
+    name = cfg.get("deployment_module", DEFAULT_DEPLOYMENT)
+    if not MODULE_PATH.fullmatch(name) or \
+            not os.path.isfile(os.path.join(BENCH, name + ".py")):
+        raise ValueError(f"deployment_module {name!r} names no module "
+                         f"bench/<path>.py")
+    return importlib.import_module(name.replace("/", "."))
 
 
 def cell_metrics(spec: dict, name: str, trace: bool) -> list:
@@ -201,63 +215,6 @@ class StoreTally:
         return dict(self.total)
 
 
-def warm_up(engine, server, client, cfg, mix, data, keys_rng) -> None:
-    """Compile every padded probe shape the cell's micro-batches can reach
-    (the engine pads each table's unique keys to a power of two, at least
-    8), then send a few requests through the client."""
-    per_table = int(mix["session_keys"]) + int(mix["fresh_keys"])
-    tables = [t["name"] for t in cfg["tables"]]
-    scalars = [t["name"] for t in deploy.tables(cfg, "scalar")]
-    pol = server.policy
-    riders = max(1, min(pol.max_batch_requests,
-                        pol.max_batch_keys // (per_table * len(tables))))
-    most = min(riders * per_table, len(data.keys))
-    p = 8
-    while True:
-        engine.query({t: data.keys[:p] for t in scalars})
-        if p >= most:
-            break
-        p <<= 1
-    zipf = traffic.Zipf(len(data.keys), mix["zipf"])
-    for _ in range(4):
-        ranks = zipf.sample(keys_rng, per_table)
-        client.query({t: data.keys[ranks] for t in tables})
-
-
-def compare(win, data, deltas, update_rows, scalar_tables) -> dict:
-    """Every answer's scalar tables, and the sampled answers' rows, against
-    the reference at the version the answer reports. Returns the counts
-    compared, each beside its limit."""
-    ref = reference.Reference(data, deltas)
-    ok = win.outcome == window.OK
-    wrong = np.zeros(len(ok), dtype=bool)
-    for v in np.unique(win.version[ok]):
-        ref.advance(int(v))
-        idx = np.flatnonzero(ok & (win.version == v))
-        keys = [win.sched.keys_of(int(i), data.keys) for i in idx]
-        cut = np.cumsum([0] + [len(k) for k in keys])
-        flat = np.concatenate(keys)
-        for t in scalar_tables:
-            found, pay = ref.scalar(t, flat)
-            got_f = np.concatenate([win.scalars[i][t][0] for i in idx])
-            got_p = np.concatenate([win.scalars[i][t][1] for i in idx])
-            bad = (got_f != found) | (got_p != pay)
-            wrong[idx] |= np.add.reduceat(bad, cut[:-1]) > 0
-        for j, i in enumerate(idx):
-            for t, values in win.rows.get(int(i), {}).items():
-                found, rows = ref.rows(t, keys[j], update_rows)
-                if not np.array_equal(values, rows):
-                    wrong[i] = True
-    stale = (win.outcome == window.STALE) | \
-        (ok & (win.version < win.min_version))
-    return {
-        "wrong_answers": [int(wrong.sum()), 0],
-        "stale_answers": [int(stale.sum()), 0],
-        "unanswered": [int((~ok & (win.outcome != window.STALE)).sum()), 0],
-        "failed_publishes": [int(np.isnan(win.pub_end).sum()), 0],
-    }
-
-
 # ---------------------------------------------------------------------------
 def use_compile_cache() -> None:
     """Keep every compiled program in the checkout's cache, none evicted: a
@@ -290,24 +247,22 @@ def run_cell(cell: dict, cfg: dict, mix: dict, metrics: list, *, seed: int,
 def _run(cell, cfg, mix, metrics, *, seed, seconds, trace, control,
          compiles, pauses, tmp) -> dict:
     import jax
+    dep = deployment(cfg)
     split = {}
     t = time.monotonic()
-    data = deploy.generate(cfg, seed)
-    sched = traffic.generate(mix, len(data.keys), seconds, seed)
-    deltas, update_rows = deploy.make_deltas(cfg, sched, seed)
+    data = dep.generate(cfg, seed)
+    sched = dep.schedule(mix, cfg, data, seconds, seed)
+    deltas, update_rows = dep.make_deltas(cfg, sched, seed)
     split["generate"] = time.monotonic() - t
-    scalar_tables = [t["name"] for t in deploy.tables(cfg, "scalar")]
-    tables = [t["name"] for t in cfg["tables"]]
 
     engine = server = tally = tracer = None
     t = time.monotonic()
     if control:
-        client = reference.ControlClient(data, deltas, update_rows,
-                                         cfg["control"])
+        client = dep.control_client(data, deltas, update_rows, cfg)
         split["build"] = time.monotonic() - t
     else:
         from repro.obs.trace import Tracer
-        engine = deploy.build_engine(cfg, data)
+        engine = dep.build_engine(cfg, data)
         split["build"] = time.monotonic() - t
         t = time.monotonic()
         _, _, build = engine.window.get(None)
@@ -315,10 +270,10 @@ def _run(cell, cfg, mix, metrics, *, seed, seconds, trace, control,
         split["upload"] = time.monotonic() - t
         if trace:
             tracer = Tracer(sample_rate=SPAN_SAMPLE, capacity=1 << 20)
-        server, client = deploy.serve(cfg, engine, tracer)
+        server, client = dep.serve(cfg, engine, tracer)
         t = time.monotonic()
-        warm_up(engine, server, client, cfg, mix, data,
-                np.random.default_rng([seed, 15]))
+        dep.warm_up(engine, server, client, cfg, mix, data,
+                    np.random.default_rng([seed, 15]))
         split["warmup"] = time.monotonic() - t
         if trace:
             tally = StoreTally(engine)
@@ -329,10 +284,9 @@ def _run(cell, cfg, mix, metrics, *, seed, seconds, trace, control,
                                    replace=False).tolist())
     sample.add(n_reads - 1)
     win = window.Window(
-        client, sched, data, tables, deltas,
-        lambda d: deploy.upserts(data, d, update_rows),
+        client, sched, data, deltas,
+        lambda d: dep.upserts(data, d, update_rows), dep.send,
         clients=int(mix["clients"]), sample=sample,
-        scalar_tables=scalar_tables,
         on_publish=tally.on_publish if tally else None)
 
     split["compiled"] = len(compiles.times)
@@ -369,7 +323,7 @@ def _run(cell, cfg, mix, metrics, *, seed, seconds, trace, control,
             lo_mono=traced["lo"], hi_mono=traced["hi"], host_spans=host))
         shutil.rmtree(os.path.join(tmp, "trace"), ignore_errors=True)
 
-    checks = compare(win, data, deltas, update_rows, scalar_tables)
+    checks = dep.compare(win, data, deltas, update_rows, cfg)
     if not joined:
         checks["unanswered"][0] += 1
 
@@ -378,7 +332,8 @@ def _run(cell, cfg, mix, metrics, *, seed, seconds, trace, control,
         t0=t0, setup_s=setup_s,
         read_due=t0 + sched.read_due, read_sent=win.sent,
         read_done=win.done, read_ok=win.outcome == window.OK,
-        read_keys=np.array([len(r) for r in sched.read_ranks]) * len(tables),
+        read_keys=np.array([sched.keys_asked(i) for i in range(n_reads)],
+                           dtype=np.int64),
         update_due=t0 + sched.update_due,
         update_visible=win.update_visible(),
         pub_start=win.pub_start, pub_end=win.pub_end,

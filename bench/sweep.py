@@ -26,24 +26,19 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-import deploy  # noqa: E402
 import run  # noqa: E402
-import traffic  # noqa: E402
 import window  # noqa: E402
 
 
-def offer(engine, client, cfg, mix, data, rate, seconds, seed) -> dict:
+def offer(dep, engine, client, cfg, mix, data, rate, seconds, seed) -> dict:
     mix = dict(mix, sessions_per_s=rate)
-    sched = traffic.generate(mix, len(data.keys), seconds, seed)
+    sched = dep.schedule(mix, cfg, data, seconds, seed)
     first = engine.latest_version + 1
-    deltas, update_rows = deploy.make_deltas(cfg, sched, seed, first)
-    tables = [t["name"] for t in cfg["tables"]]
+    deltas, update_rows = dep.make_deltas(cfg, sched, seed, first)
     win = window.Window(
-        client, sched, data, tables, deltas,
-        lambda d: deploy.upserts(data, d, update_rows),
-        clients=int(mix["clients"]), sample=set(),
-        scalar_tables=[t["name"] for t in deploy.tables(cfg, "scalar")],
-        acked=first - 1)
+        client, sched, data, deltas,
+        lambda d: dep.upserts(data, d, update_rows), dep.send,
+        clients=int(mix["clients"]), sample=set(), acked=first - 1)
     t0 = time.monotonic() + 0.05
     win.start(t0, seconds, grace_s=20.0)
     win.join()
@@ -83,14 +78,15 @@ def main(argv=None) -> int:
         print("no TPU", file=sys.stderr)
         return 2
     run.use_compile_cache()
-    data = deploy.generate(cfg, args.seed)
-    engine = deploy.build_engine(cfg, data)
-    server, client = deploy.serve(cfg, engine)
+    dep = run.deployment(cfg)
+    data = dep.generate(cfg, args.seed)
+    engine = dep.build_engine(cfg, data)
+    server, client = dep.serve(cfg, engine)
     try:
-        run.warm_up(engine, server, client, cfg, mix, data,
+        dep.warm_up(engine, server, client, cfg, mix, data,
                     np.random.default_rng([args.seed, 15]))
         for k, rate in enumerate(float(r) for r in args.rates.split(",")):
-            print(json.dumps(offer(engine, client, cfg, mix, data, rate,
+            print(json.dumps(offer(dep, engine, client, cfg, mix, data, rate,
                                    args.seconds, args.seed + k)), flush=True)
     finally:
         server.close()

@@ -2,13 +2,15 @@
 
     JAX_PLATFORMS=cpu python -m pytest -q bench/test_correctness.py
 
-Each cell's whole run (data, build, warm-up, window, comparison) goes
-through ``run.run_cell``, skipping only the harness's look for a chip. A
-sound run must come out correct; the control (the reference in the
-program's place with the configuration's ``control`` guarantee broken)
-and each fault planted in the program underneath must come out not
-correct. The cells run on one chip, so no exchange between chips exists
-to leave out.
+Every cell of ``BENCHMARK.json``, at its deployment's ``small`` cut, goes
+through ``run.run_cell`` whole (data, build, warm-up, window, comparison),
+skipping only the harness's look for a chip. A sound run must come out
+correct; the control (the reference in the program's place with the
+configuration's ``control`` guarantee broken) and each fault planted in the
+program underneath must come out not correct: the generic faults of
+``bench/faults.py`` wherever the cell's tables have the part they alter,
+and the deployment's own ``FAULTS``. The cells run on one chip, so no
+exchange between chips exists to leave out.
 """
 from __future__ import annotations
 
@@ -16,27 +18,31 @@ import os
 import subprocess
 import sys
 
-import numpy as np
 import pytest
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, BENCH)
 
+import faults  # noqa: E402
 import run  # noqa: E402
 
-CELLS = ["bili.ycsb-b.zipf99", "bili.ycsb-b.above-knee", "din.attr.sessions"]
+SPEC = run.load_json(os.path.join(run.ROOT, "BENCHMARK.json"))
+CELLS = [w["name"] for w in SPEC["workloads"]]
 
 
 def small(name: str):
-    """The cell's own configuration and mix at a size the CPU runs in
-    seconds."""
-    spec = run.load_json(os.path.join(run.ROOT, "BENCHMARK.json"))
-    cell, cfg, mix = run.cell_files(spec, name)
-    cfg["n_items"] = 20_000
-    mix["fresh_keys"] = max(mix["fresh_keys"] // 16, 8)
-    mix["session_keys"] //= 4
-    mix["sessions_per_s"] = min(mix["sessions_per_s"], 20.0)
-    return cell, cfg, mix, run.cell_metrics(spec, name, False)
+    """The cell's own configuration and mix at its deployment's CPU cut."""
+    cell, cfg, mix = run.cell_files(SPEC, name)
+    cfg, mix = run.deployment(cfg).small(cfg, mix)
+    return cell, cfg, mix, run.cell_metrics(SPEC, name, False)
+
+
+def cell_faults(name: str) -> list:
+    """The generic faults whose part the cell's tables have, and its
+    deployment's own."""
+    _, cfg, mix, _ = small(name)
+    return [f for f in faults.GENERIC + run.deployment(cfg).FAULTS
+            if f.applies(cfg, mix)]
 
 
 def run_small(name: str, seed: int = 2**31 + 5, control: bool = False):
@@ -61,77 +67,13 @@ def test_control_is_not_correct(name):
     assert res["checks"]["wrong_answers"]["value"] > 0
 
 
-def _drop_half(monkeypatch):
-    """Half of each batch left out: the engine answers the second half of
-    every table's keys as absent."""
-    from repro.core.engine import MultiTableEngine
-    orig = MultiTableEngine._finish
-
-    def finish(self, inflight):
-        out = orig(self, inflight)
-        for t in out.tables.values():
-            half = len(t.found) // 2
-            t.found[half:] = False
-            if t.payloads is not None:
-                t.payloads[half:] = 0
-            if t.values is not None:
-                t.values[half:] = 0
-        return out
-    monkeypatch.setattr(MultiTableEngine, "_finish", finish)
-
-
-def _alter_probe(monkeypatch):
-    """An answer altered where it is produced: the device probe flips the
-    low bit of every payload it returns."""
-    from repro.core import lookup as lk
-    orig = lk.lookup
-
-    def lookup(*args, **kw):
-        found, p_hi, p_lo = orig(*args, **kw)
-        return found, p_hi, p_lo ^ np.uint32(1)
-    monkeypatch.setattr(lk, "lookup", lookup)
-
-
-def _alter_rows(monkeypatch):
-    """An answer altered where it is produced: the hybrid store's gather
-    flips one byte of every row it returns."""
-    from repro.core.hybrid_store import HybridKVStore
-    orig = HybridKVStore.get_batch
-
-    def get_batch(self, keys, admit=True):
-        found, rows = orig(self, keys, admit)
-        rows[:, 0] ^= 1
-        return found, rows
-    monkeypatch.setattr(HybridKVStore, "get_batch", get_batch)
-
-
-def _unchanged_state(monkeypatch):
-    """A step that returns its state unchanged: a delta publish installs its
-    version over the previous build, the delta never applied."""
-    from repro.core.engine import MultiTableEngine
-
-    def publish_delta(self, version, upserts=None, deletes=None):
-        with self._publish_lock:
-            _, _, prev = self.window.get(None)
-            self.window.publish(version, prev)
-    monkeypatch.setattr(MultiTableEngine, "publish_delta", publish_delta)
-
-
-FAULTS = [
-    ("bili.ycsb-b.zipf99", _drop_half), ("din.attr.sessions", _drop_half),
-    ("bili.ycsb-b.zipf99", _alter_probe), ("din.attr.sessions", _alter_probe),
-    ("bili.ycsb-b.zipf99", _alter_rows),
-    ("bili.ycsb-b.zipf99", _unchanged_state),
-    ("bili.ycsb-b.above-knee", _drop_half),
-    ("bili.ycsb-b.above-knee", _alter_rows),
-    ("bili.ycsb-b.above-knee", _unchanged_state),
-]
+FAULTS = [(name, f) for name in CELLS for f in cell_faults(name)]
 
 
 @pytest.mark.parametrize("name,fault", FAULTS,
-                         ids=[f"{n}-{f.__name__[1:]}" for n, f in FAULTS])
+                         ids=[f"{n}-{f.name}" for n, f in FAULTS])
 def test_fault_is_not_correct(name, fault, monkeypatch):
-    fault(monkeypatch)
+    fault.plant(monkeypatch)
     res = run_small(name)
     assert not res["correct"]
     assert res["checks"]["wrong_answers"]["value"] > 0
@@ -148,12 +90,12 @@ def test_no_tpu_exits_without_result():
 
 
 def test_every_cell_and_metric_has_its_files():
-    spec = run.load_json(os.path.join(run.ROOT, "BENCHMARK.json"))
-    for w in spec["workloads"]:
-        cell, cfg, mix = run.cell_files(spec, w["name"])
+    for name in CELLS:
+        cell, cfg, mix = run.cell_files(SPEC, name)
         assert cfg["name"] == cell["config"]
+        assert run.deployment(cfg) is not None
         for trace in (False, True):
-            for m in run.cell_metrics(spec, w["name"], trace):
+            for m in run.cell_metrics(SPEC, name, trace):
                 assert os.path.exists(run.metric_reader(m["name"])), \
                     m["name"]
 
@@ -162,3 +104,13 @@ def test_split_metric_takes_its_quantitys_reader():
     reader = os.path.join(BENCH, "metrics", "engine.finish_ms.py")
     assert run.metric_reader("engine.finish_ms.throughput") == reader
     assert run.metric_reader("engine.finish_ms") == reader
+
+
+def test_config_without_module_runs_the_default():
+    default = run.deployment({})
+    assert default.__name__ == "deployments.default"
+    assert run.deployment({"deployment_module": "deployments/default"}) \
+        is default
+    for name in ("../run", "/etc/passwd", "deployments/none", "a.b"):
+        with pytest.raises(ValueError):
+            run.deployment({"deployment_module": name})

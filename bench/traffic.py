@@ -51,6 +51,11 @@ class Schedule:
     update_due: np.ndarray        # float64 [U], sorted
     update_ranks: np.ndarray      # int64 [U]
     publish_interval_s: float
+    tables: list                  # the tables every request asks
+
+    def keys_asked(self, i: int) -> int:
+        """Keys request ``i`` asks for, over every table and round."""
+        return len(self.read_ranks[i]) * len(self.tables)
 
     def keys_of(self, i: int, item_keys: np.ndarray) -> np.ndarray:
         """Request ``i``'s keys, in the order they are sent."""
@@ -79,7 +84,8 @@ def _shaped(shape_rng, order_rng, draw, n):
     return vals[order_rng.permutation(n)]
 
 
-def generate(mix: dict, n_items: int, seconds: float, seed: int) -> Schedule:
+def generate(mix: dict, n_items: int, seconds: float, seed: int,
+             tables: list) -> Schedule:
     shape = np.random.default_rng(int(mix["shape_seed"]))
     order = np.random.default_rng([seed, 11])
     keys_rng = np.random.default_rng([seed, 12])
@@ -127,4 +133,5 @@ def generate(mix: dict, n_items: int, seconds: float, seed: int) -> Schedule:
     return Schedule(read_due=due, read_ranks=ranks, miss_keys=misses,
                     update_due=upd_due, update_ranks=upd_ranks,
                     publish_interval_s=float(mix.get("publish_interval_s",
-                                                     1.0)))
+                                                     1.0)),
+                    tables=list(tables))

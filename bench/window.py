@@ -8,9 +8,15 @@ timed from when it was due until the ``client.update`` call that publishes
 it returns. Reads ask for ``min_version`` of the newest acknowledged
 version wherever updates flow (read-your-writes), so an answer older than
 an acknowledged publish fails as stale.
+
+What a request sends, and what of its answer is kept for the comparison,
+is the deployment's ``send``: the window's one call per request. It may
+make several ``client.query`` calls (dependent rounds) and returns an
+``Answer``, stamped when its last round returned.
 """
 from __future__ import annotations
 
+import dataclasses
 import threading
 import time
 
@@ -19,19 +25,26 @@ import numpy as np
 OK, SHED, STALE, FAILED, MISSING = 0, 1, 2, 3, 4
 
 
+@dataclasses.dataclass
+class Answer:
+    """What ``send`` returns for one request."""
+    done: float                   # time.monotonic() as the last round returned
+    version: int                  # the version the answer is judged at
+    kept: object                  # what the deployment's ``compare`` reads
+
+
 class Window:
-    def __init__(self, client, sched, data, table_names, deltas, upserts,
-                 *, clients: int, sample: set, scalar_tables: list,
-                 on_publish=None, acked: int = 1):
+    def __init__(self, client, sched, data, deltas, upserts, send, *,
+                 clients: int, sample: set, on_publish=None, acked: int = 1):
         self.client = client
         self.sched = sched
         self.data = data
-        self.table_names = table_names
         self.deltas = deltas
         self.upserts = upserts          # delta -> client.update argument
+        # (client, sched, data, i, consistency, timeout, sampled) -> Answer
+        self.send = send
         self.n_clients = clients
-        self.sample = sample
-        self.scalar_tables = scalar_tables
+        self.sample = sample            # requests whose whole answer is kept
         self.on_publish = on_publish
         n = len(sched.read_due)
         self.sent = np.full(n, np.nan)
@@ -39,8 +52,7 @@ class Window:
         self.outcome = np.full(n, MISSING, dtype=np.int8)
         self.version = np.zeros(n, dtype=np.int64)
         self.min_version = np.zeros(n, dtype=np.int64)
-        self.scalars: list = [None] * n      # {table: (found, payloads)}
-        self.rows: dict = {}                 # sampled i -> {table: values}
+        self.kept: list = [None] * n    # Answer.kept of each answered i
         self.errors: list = []
         self.pub_start = np.full(len(deltas), np.nan)
         self.pub_end = np.full(len(deltas), np.nan)
@@ -59,8 +71,6 @@ class Window:
                 self._next += 1
             if i >= n:
                 return
-            keys = sched.keys_of(i, self.data.keys)
-            request = {t: keys for t in self.table_names}
             delay = t0 + sched.read_due[i] - time.monotonic()
             if delay > 0:
                 time.sleep(delay)
@@ -70,9 +80,10 @@ class Window:
                 else None
             self.sent[i] = time.monotonic()
             try:
-                resp = self.client.query(
-                    request, consistency=consistency,
-                    timeout=max(give_up - time.monotonic(), 0.001))
+                ans = self.send(self.client, sched, self.data, i,
+                                consistency,
+                                max(give_up - time.monotonic(), 0.001),
+                                i in self.sample)
             except ShedError:
                 self.outcome[i] = SHED
                 continue
@@ -84,16 +95,10 @@ class Window:
                 self.outcome[i] = FAILED
                 self.errors.append(repr(e))
                 continue
-            self.done[i] = time.monotonic()
+            self.done[i] = ans.done
             self.outcome[i] = OK
-            self.version[i] = resp.version
-            self.scalars[i] = {t: (np.array(resp.tables[t].found),
-                                   np.array(resp.tables[t].payloads))
-                               for t in self.scalar_tables}
-            if i in self.sample:
-                self.rows[i] = {t: np.array(resp.tables[t].values)
-                                for t in self.table_names
-                                if t not in self.scalar_tables}
+            self.version[i] = ans.version
+            self.kept[i] = ans.kept
 
     def _publish(self, t0: float) -> None:
         for k, d in enumerate(self.deltas):
